@@ -52,10 +52,13 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=3)
     args = ap.parse_args()
 
+    # coordinator and parties on the CPU, said explicitly: a parent that
+    # took an accelerator would lock its children out of it
+    os.environ["JAX_PLATFORMS"] = "cpu"
     from repro.data.healthlnk import generate_healthlnk
     from repro.obs import Tracer
     from repro.obs.distributed import write_chrome_trace
-    from repro.runtime import ReflexClient, connect_tcp
+    from repro.runtime import ReflexClient, connect_tcp, party_env
 
     os.makedirs(OUT_DIR, exist_ok=True)
     here = os.path.dirname(os.path.abspath(__file__))
@@ -66,7 +69,7 @@ def main() -> int:
                 sys.executable, run_parties,
                 "--party", str(p), "--base-port", str(args.base_port),
             ],
-            env=dict(os.environ),
+            env=party_env(p, "cpu"),
         )
         for p in range(3)
     ]

@@ -17,78 +17,10 @@ import time
 import jax
 
 from repro.core.noise import NoTrim, RevealNoise, TruncatedLaplace
-from repro.data import generate_healthlnk, plaintext_oracle
+from repro.data import check_rows, generate_healthlnk, plaintext_oracle
 from repro.data.queries import QUERY_SQL
 from repro.runtime import ReflexClient
 from repro.service import PrivacyAccountant
-
-
-def check(qname, result, oracle):
-    """Validate one query result against its plaintext oracle.
-
-    Every query is genuinely checked — the old generic version fell through
-    to an unvalidated "(table)" True for comorbidity / diag_breakdown /
-    SUM / AVG (which is what hid the projection_join pair-oracle mismatch
-    until PR 4 added the pair branch)."""
-    rows = result.rows
-    if qname == "comorbidity":
-        shown = {int(v): int(c) for v, c in zip(rows["major_icd9"], rows["cnt"])}
-        # the sort is on COUNT(*) alone, so the LIMIT boundary may break
-        # count-ties differently than the oracle's (count, value) order.
-        # Require: count multiset matches; every value strictly above the
-        # boundary count appears with its exact count (only boundary TIES
-        # may substitute); and any overlap agrees exactly
-        boundary = min(oracle.values(), default=0)
-        ok = (
-            sorted(shown.values()) == sorted(oracle.values())
-            and all(shown.get(v) == c
-                    for v, c in oracle.items() if c > boundary)
-            and all(shown[v] == c for v, c in oracle.items() if v in shown)
-        )
-        return shown, ok
-    if qname == "diag_breakdown":
-        shown = {
-            (int(a), int(b)): int(c)
-            for a, b, c in zip(rows["major_icd9"], rows["diag"], rows["cnt"])
-        }
-        return shown, shown == oracle
-    if qname == "dosage_sum":
-        shown = int(rows["total"][0])
-        return shown, shown == oracle
-    if qname == "dosage_avg":
-        shown = {k: int(rows[k][0]) for k in ("avg_dosage_sum",
-                                              "avg_dosage_cnt", "avg_dosage")}
-        ok = (shown["avg_dosage_sum"] == oracle["sum"]
-              and shown["avg_dosage_cnt"] == oracle["cnt"]
-              and shown["avg_dosage"] == oracle["avg"])
-        return shown["avg_dosage"], ok
-    if qname == "med_dosage_sum":
-        shown = {int(k): int(v) for k, v in zip(rows["med"], rows["total"])}
-        return shown, shown == oracle
-    if qname == "repeat_diagnoses":
-        shown = {int(k): int(v)
-                 for k, v in zip(rows["major_icd9"], rows["cnt"])}
-        return shown, shown == oracle
-    if qname == "med_dosage_avg":
-        # the service's post_reveal already folded (sum, cnt) -> mean
-        shown = {int(k): int(v) for k, v in zip(rows["med"], rows["mean"])}
-        return shown, shown == {k: v["avg"] for k, v in oracle.items()}
-    if qname == "projection_join":
-        # the oracle is the sorted (pid, dosage) pair set
-        shown = sorted({(int(p), int(v))
-                        for p, v in zip(rows["pid"], rows["dosage"])})
-        return shown, shown == oracle
-    if qname in ("dosage_min", "dosage_max"):
-        col = "lo" if qname == "dosage_min" else "hi"
-        if oracle is None:  # empty selection: nothing may be revealed
-            return None, len(rows[col]) == 0
-        shown = int(rows[col][0])
-        return shown, shown == oracle
-    if "cnt" in rows and len(rows["cnt"]) == 1:
-        shown = int(rows["cnt"][0])
-        return shown, shown == oracle
-    shown = sorted(set(rows["pid"].tolist()))
-    return shown, shown == oracle
 
 
 def main():
@@ -116,7 +48,7 @@ def main():
         session = svc.session("example")
         for qname, sql in QUERY_SQL.items():
             res = session.submit(sql)
-            shown, ok = check(qname, res, plaintext_oracle(qname, plain))
+            shown, ok = check_rows(qname, res.rows, plaintext_oracle(qname, plain))
             print(
                 f"{qname:<16}{mode:<18}{res.report.total_seconds:>8.2f}"
                 f"{res.report.total_bytes / 2**20:>12.3f}"
@@ -201,4 +133,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

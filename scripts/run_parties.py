@@ -9,7 +9,13 @@ One process per party (the production topology)::
 
 or a compose-style launcher that forks all three and waits::
 
-    PYTHONPATH=src python scripts/run_parties.py --party all
+    PYTHONPATH=src python scripts/run_parties.py --party all [--platform cpu|tpu]
+
+Each forked party gets an explicit device (``repro.runtime.party_env``):
+``--platform cpu`` (the default, CI's topology) pins all three to the CPU;
+``--platform tpu`` gives party p chip p of a multi-chip TPU host and
+nothing else. The launcher itself starts no JAX backend, so it holds no chip.
+A single ``--party p`` runs on whatever its environment selects.
 
 Parties listen on ``base_port + party`` and build the pair mesh among
 themselves (party p dials every lower-numbered party; higher-numbered
@@ -41,7 +47,14 @@ def serve_one(party: int, host: str, base_port: int) -> None:
     for q in range(3):
         if q > party:
             tr.wait_for(q, timeout=60.0)
-    print(f"[party {party}] mesh up; serving", flush=True)
+    import jax
+
+    dev = jax.devices()[0]
+    print(
+        f"[party {party}] mesh up on {dev.platform} ({dev.device_kind}); "
+        "serving",
+        flush=True,
+    )
     server = PartyServer(party, tr, tr)
     try:
         server.serve()
@@ -50,9 +63,11 @@ def serve_one(party: int, host: str, base_port: int) -> None:
     print(f"[party {party}] shut down", flush=True)
 
 
-def launch_all(host: str, base_port: int) -> int:
-    """Compose-style launcher: three party processes, torn down together."""
-    env = dict(os.environ)
+def launch_all(host: str, base_port: int, platform: str) -> int:
+    """Compose-style launcher: three party processes, each on an explicit
+    device (see ``party_env``), torn down together."""
+    from repro.runtime.party import party_env
+
     procs = [
         subprocess.Popen(
             [
@@ -60,7 +75,7 @@ def launch_all(host: str, base_port: int) -> int:
                 "--party", str(p), "--host", host,
                 "--base-port", str(base_port),
             ],
-            env=env,
+            env=party_env(p, platform),
         )
         for p in range(3)
     ]
@@ -85,9 +100,14 @@ def main() -> int:
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--base-port", type=int, default=9600,
                     help="party p listens on base-port + p (default 9600)")
+    ap.add_argument("--platform", choices=("cpu", "tpu"), default="cpu",
+                    help="device of each party forked by --party all")
     args = ap.parse_args()
     if args.party == "all":
-        return launch_all(args.host, args.base_port)
+        return launch_all(args.host, args.base_port, args.platform)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     serve_one(int(args.party), args.host, args.base_port)
     return 0
 

@@ -12,6 +12,11 @@ divergence from the single-process oracle:
 * each party's wire bytes must equal its exchange-log bytes and the
   report's ledger bytes (audited inside RemoteEngine; re-printed here).
 
+Every process runs on the CPU, set explicitly: the parties through
+``repro.runtime.party_env(p, "cpu")``, the coordinator and its oracle by
+``JAX_PLATFORMS=cpu`` before JAX starts. The TPU form of this check, one
+chip per party, is ``python chip_smoke.py --parties``.
+
 Exit code 0 = all checks passed.
 
 Usage::
@@ -33,10 +38,14 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=3)
     args = ap.parse_args()
 
+    # parties and the in-process oracle all on the CPU, said explicitly: a
+    # parent that took an accelerator would lock its children out of it,
+    # and the audit compares the oracle and the parties byte by byte
+    os.environ["JAX_PLATFORMS"] = "cpu"
     from repro.config import RuntimeConfig
     from repro.data.healthlnk import generate_healthlnk
     from repro.data.queries import QUERY_SQL
-    from repro.runtime import ReflexClient, connect_tcp
+    from repro.runtime import ReflexClient, connect_tcp, party_env
 
     cfg = RuntimeConfig(join_algo="sortmerge")
     goldens = ["dosage_study", "projection_join"]
@@ -48,7 +57,7 @@ def main() -> int:
                 sys.executable, os.path.join(here, "run_parties.py"),
                 "--party", str(p), "--base-port", str(args.base_port),
             ],
-            env=dict(os.environ),
+            env=party_env(p, "cpu"),
         )
         for p in range(3)
     ]

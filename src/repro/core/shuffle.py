@@ -14,8 +14,9 @@ and cannot link positions. Since every party is ignorant of at least one
 Costs (Table 1 of the paper): 3 rounds (constant), each hop moves the whole
 table once => ``3 * N * M`` bytes per party for N rows of M bytes. The
 computational cost of *applying* a permutation is a row gather — the hot loop
-that ``repro.kernels.shuffle_gather`` implements as a blocked Pallas kernel
-(HBM -> VMEM row tiles); the jnp fallback is ``jnp.take``.
+that ``repro.kernels.shuffle_gather`` implements as a Pallas kernel (the
+table staged whole in VMEM, one row copy per output row; tables too large for
+that stage take the XLA gather); with kernels off it is ``jnp.take``.
 """
 from __future__ import annotations
 
@@ -72,16 +73,27 @@ def _rerandomize(col: Share, prf: PRFSetup, tag: int) -> Share:
     return BShare(col.shares ^ zero_share_xor(p, col.shape, col.ring))
 
 
-def secure_shuffle(
-    cols: Dict[str, Share],
-    prf: PRFSetup,
-    gather_fn=None,
-) -> Dict[str, Share]:
-    """Shuffle all columns of a table with one hidden common permutation.
+def _row_take():
+    """Row gather of a (3, N, ...) share array by a hop permutation: the
+    ``shuffle_gather`` kernel when kernels are on (all three shares of a
+    column as one (N, 3 * ...) table, one launch), else ``jnp.take``."""
+    from ..kernels import kernels_enabled
 
-    ``gather_fn(shares, perm)`` may be supplied to route the row gather through
-    the Pallas kernel; default is ``jnp.take`` along the row axis.
-    """
+    if not kernels_enabled():
+        return lambda shares, perm: jnp.take(shares, perm, axis=1)
+    from ..kernels.shuffle_gather.ops import gather_rows
+
+    def take(shares, perm):
+        n = shares.shape[1]
+        rows = jnp.moveaxis(shares, 1, 0).reshape(n, -1)
+        out = gather_rows(rows, perm).reshape((n, 3) + shares.shape[2:])
+        return jnp.moveaxis(out, 0, 1)
+
+    return take
+
+
+def secure_shuffle(cols: Dict[str, Share], prf: PRFSetup) -> Dict[str, Share]:
+    """Shuffle all columns of a table with one hidden common permutation."""
     if not cols:
         return cols
     first = next(iter(cols.values()))
@@ -89,19 +101,7 @@ def secure_shuffle(
     row_bytes = sum(
         c.ring.bytes * (c.size // max(c.shape[0], 1)) for c in cols.values()
     )
-    if gather_fn is None:
-        from ..kernels import kernels_enabled
-
-        if kernels_enabled():
-            from ..kernels.shuffle_gather.ops import gather_rows
-
-            def gather_fn(shares, perm):
-                # shares: (3, N, ...) -> flatten trailing dims into columns
-                flat = shares.reshape(3, shares.shape[1], -1)
-                out = jnp.stack([gather_rows(flat[i], perm) for i in range(3)])
-                return out.reshape(shares.shape)
-
-    take = gather_fn or (lambda shares, perm: jnp.take(shares, perm, axis=1))
+    take = _row_take()
 
     with fused_scope("shuffle", rounds=HOPS):
         out = dict(cols)
@@ -117,11 +117,7 @@ def secure_shuffle(
     return out
 
 
-def inverse_shuffle(
-    cols: Dict[str, Share],
-    prf: PRFSetup,
-    gather_fn=None,
-) -> Dict[str, Share]:
+def inverse_shuffle(cols: Dict[str, Share], prf: PRFSetup) -> Dict[str, Share]:
     """Undo ``secure_shuffle(cols, prf)``: apply the hop permutations inverted
     and in reverse order. Same round/byte pattern as the forward shuffle (each
     hop is one table move + resharing); the re-randomization tags differ so
@@ -134,18 +130,7 @@ def inverse_shuffle(
     row_bytes = sum(
         c.ring.bytes * (c.size // max(c.shape[0], 1)) for c in cols.values()
     )
-    if gather_fn is None:
-        from ..kernels import kernels_enabled
-
-        if kernels_enabled():
-            from ..kernels.shuffle_gather.ops import gather_rows
-
-            def gather_fn(shares, perm):
-                flat = shares.reshape(3, shares.shape[1], -1)
-                out = jnp.stack([gather_rows(flat[i], perm) for i in range(3)])
-                return out.reshape(shares.shape)
-
-    take = gather_fn or (lambda shares, perm: jnp.take(shares, perm, axis=1))
+    take = _row_take()
 
     with fused_scope("shuffle", rounds=HOPS):
         out = dict(cols)

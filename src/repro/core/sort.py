@@ -10,9 +10,9 @@ oblivious select per payload column (1 AND-word). Total rounds
 O(log^2 N) — vs. the shuffle's O(1), which is exactly the paper's argument for
 replacing Shrinkwrap's sort with a shuffle (Fig. 5a / Fig. 8).
 
-The per-stage compare-exchange is the compute hot spot; it is also provided as
-a Pallas kernel (``repro.kernels.bitonic_stage``) with this module's jnp path
-as the oracle.
+The per-stage compare-exchange is the compute hot spot; with fused kernels on,
+its conditional swap runs as one ``repro.kernels.bitonic_stage`` launch, with
+this module's gate-by-gate path as the oracle.
 """
 from __future__ import annotations
 
@@ -21,9 +21,10 @@ from typing import Dict, List, Sequence, Union
 
 import jax.numpy as jnp
 
+from ..kernels import fusion_enabled
 from .circuits import and_bit, eq, lt, or_bit
-from .ledger import active_ledger
-from .prf import PRFSetup
+from .ledger import active_ledger, log_comm
+from .prf import PRFSetup, zero_share_xor
 from .sharing import AShare, BShare, and_, const_b
 
 __all__ = [
@@ -116,9 +117,18 @@ def _stage(
     names = list(cols)
     own = BShare(jnp.stack([cols[nm].shares for nm in names], axis=1))  # (3,C,n)
     other = own.take(partner, axis=1)
-    m3 = BShare(jnp.broadcast_to(mask.shares[:, None, :], own.shares.shape))
-    d = and_(m3, own ^ other, prf.fold(9000 + 31 * k + 7 * j))
-    new = own ^ d
+    p_swap = prf.fold(9000 + 31 * k + 7 * j)
+    if fusion_enabled():
+        # the same AND (same alpha, same ledger entry) with the select's
+        # XORs fused around it: one bitonic_stage launch
+        from ..kernels.bitonic_stage.ops import stage_swap
+
+        alpha = zero_share_xor(p_swap, own.shape, own.ring)
+        new = BShare(stage_swap(mask.shares, own.shares, other.shares, alpha))
+        log_comm("and", 1, own.size * own.ring.bytes)
+    else:
+        m3 = BShare(jnp.broadcast_to(mask.shares[:, None, :], own.shares.shape))
+        new = own ^ and_(m3, own ^ other, p_swap)
     return {nm: BShare(new.shares[:, i]) for i, nm in enumerate(names)}
 
 

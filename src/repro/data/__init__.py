@@ -1,4 +1,5 @@
 from .healthlnk import (  # noqa: F401
+    check_rows,
     generate_healthlnk,
     plaintext_oracle,
     ICD9_CIRCULATORY,

@@ -20,9 +20,13 @@ fused body is exactly the simulation hot loop):
                       payload columns
 
 Each kernel directory has ``<name>.py`` (pl.pallas_call + BlockSpec),
-``ops.py`` (jit'd wrapper with padding + interpret-mode switch), and
-``ref.py`` (pure-jnp oracle). CPU validation uses ``interpret=True``; the
-BlockSpecs are sized for TPU v5e VMEM (~16 MiB/core).
+``ops.py`` (jit'd wrapper with padding), and ``ref.py`` (pure-jnp oracle).
+Where a kernel runs is decided by :func:`interpret_mode` alone: compiled by
+Mosaic on ``tpu``, the Pallas interpreter on ``cpu`` (the test suite runs
+under ``JAX_PLATFORMS=cpu``), and an error on any other platform — there is
+no silent fallback. The BlockSpecs are sized for TPU v5e VMEM; every kernel
+is compiled for a described ``v5e:2x2`` topology in
+``tests/test_tpu_compile.py``.
 
 Switches
 --------
@@ -51,9 +55,35 @@ import threading
 from collections import Counter
 from typing import Dict, Iterator, Optional
 
+import jax
+import numpy as np
+
 from repro.config import current_config
 
 _STATE = threading.local()
+
+
+def interpret_mode(dtype) -> bool:
+    """``interpret=`` for a ``pallas_call`` over ``dtype`` operands on the
+    current default backend: True on ``cpu`` (the Pallas interpreter), False
+    on ``tpu`` (compiled by Mosaic). Any other platform raises, and so does a
+    64-bit operand on ``tpu`` (the ring-64 build): Mosaic has no 64-bit
+    integer vectors, so the error is raised here with its reason instead of
+    deep inside the compiler."""
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform != "tpu":
+        raise RuntimeError(
+            f"Pallas kernels run compiled on tpu or interpreted on cpu; the "
+            f"default backend is {platform!r}"
+        )
+    if np.dtype(dtype).itemsize == 8:
+        raise NotImplementedError(
+            f"Pallas kernels on tpu support the 32-bit ring only, got "
+            f"{np.dtype(dtype)} operands (ring-64 runs with use_pallas=False)"
+        )
+    return False
 
 
 def kernels_enabled() -> bool:

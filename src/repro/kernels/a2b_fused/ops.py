@@ -8,10 +8,9 @@ from __future__ import annotations
 
 from typing import Tuple
 
-import jax
 import jax.numpy as jnp
 
-from .. import record_launch
+from .. import interpret_mode, record_launch
 from ...core.ledger import fused_scope, log_comm
 from ...core.prf import PRFSetup, zero_share_add, zero_share_xor
 from ...core.sharing import AShare, BShare
@@ -58,10 +57,9 @@ def a2b_fused(x: AShare, prf: PRFSetup, width: int) -> BShare:
     else:
         block = _pick_block(n, BLOCK)
         xs, al = _flat_pad([xs, al], n, block)
+        interpret = interpret_mode(xs.dtype)
         record_launch("a2b_fused")
-        out = a2b_kernel(
-            xs, al, shifts, interpret=jax.default_backend() != "tpu", block=block
-        )
+        out = a2b_kernel(xs, al, shifts, interpret=interpret, block=block)
     # Ledger: identical to the two unfused ks_add invocations.
     for _ in range(2):
         with fused_scope("ks_add", rounds=1 + levels):
@@ -95,8 +93,9 @@ def bit2a_fused(b: BShare, prf: PRFSetup) -> AShare:
     else:
         block = _pick_block(n, BLOCK)
         bs, al = _flat_pad([bs, al], n, block)
+        interpret = interpret_mode(bs.dtype)
         record_launch("bit2a_fused")
-        out = bit2a_kernel(bs, al, interpret=jax.default_backend() != "tpu", block=block)
+        out = bit2a_kernel(bs, al, interpret=interpret, block=block)
     for _ in range(2):
         log_comm("mul", 1, lanes * ring.bytes)
     return AShare(out[:, :n].reshape((3,) + shape))

@@ -17,10 +17,9 @@ from __future__ import annotations
 
 from typing import Tuple
 
-import jax
 import jax.numpy as jnp
 
-from .. import record_launch
+from .. import interpret_mode, record_launch
 from ...core.ledger import log_comm
 from ...core.prf import PRFSetup, zero_share_xor
 from ...core.sharing import BShare
@@ -65,11 +64,9 @@ def ks_levels_fused(
     else:
         block = _pick_block(n, BLOCK)
         gs, ps, al = _flat_pad([gs, ps, al], n, block)
+        interpret = interpret_mode(gs.dtype)
         record_launch("ks_prefix")
-        out = ks_prefix(
-            gs, ps, al, shifts,
-            interpret=jax.default_backend() != "tpu", block=block,
-        )
+        out = ks_prefix(gs, ps, al, shifts, interpret=interpret, block=block)
     for _ in shifts:
         log_comm("and", 1, 2 * lanes * ring.bytes)
     return BShare(out[:, :n].reshape((3,) + shape))
@@ -95,10 +92,9 @@ def and_fold_fused(v: BShare, prf: PRFSetup, width: int) -> BShare:
     else:
         block = _pick_block(n, BLOCK)
         vs, al = _flat_pad([vs, al], n, block)
+        interpret = interpret_mode(vs.dtype)
         record_launch("and_fold")
-        out = and_fold(
-            vs, al, shifts, interpret=jax.default_backend() != "tpu", block=block
-        )
+        out = and_fold(vs, al, shifts, interpret=interpret, block=block)
     for _ in shifts:
         log_comm("and", 1, lanes * ring.bytes)
     return BShare(out[:, :n].reshape((3,) + shape))

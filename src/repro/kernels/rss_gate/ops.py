@@ -1,12 +1,12 @@
 """jit'd public wrapper for rss_gate: pads lanes to the block size, flattens
-arbitrary trailing shapes, and dispatches to the kernel (interpret=True on
-CPU) or the jnp reference."""
+arbitrary trailing shapes, and dispatches to the kernel (compiled on TPU,
+interpreted on CPU; see ``repro.kernels.interpret_mode``) or, with
+``use_kernel=False``, the jnp reference."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
-from .. import record_launch
+from .. import interpret_mode, record_launch
 from .ref import rss_gate_ref
 from .rss_gate import BLOCK, rss_gate
 
@@ -18,6 +18,7 @@ def gate(xs, ys, alpha, boolean: bool = True, use_kernel: bool = True, block: in
     xs, ys, alpha = jnp.broadcast_arrays(xs, ys, alpha)
     if not use_kernel or xs.size == 0:  # pallas_call cannot slice 0-lane operands
         return rss_gate_ref(xs, ys, alpha, boolean)
+    interpret = interpret_mode(xs.dtype)
     record_launch("rss_gate")
     shape = xs.shape
     flat = lambda a: a.reshape(3, -1)
@@ -28,5 +29,5 @@ def gate(xs, ys, alpha, boolean: bool = True, use_kernel: bool = True, block: in
     if pad:
         padf = lambda a: jnp.pad(a, ((0, 0), (0, pad)))
         x, y, al = padf(x), padf(y), padf(al)
-    out = rss_gate(x, y, al, boolean=boolean, interpret=jax.default_backend() != "tpu", block=block)
+    out = rss_gate(x, y, al, boolean=boolean, interpret=interpret, block=block)
     return out[:, :n].reshape(shape)

@@ -1,26 +1,37 @@
 """jit'd wrapper: pads rows to the block size (identity-mapping pad indices so
-padded rows gather from themselves), falls back to XLA gather for tables too
-large for a whole-table VMEM stage."""
+padded rows gather from themselves) and routes tables whose VMEM image is
+too large for the whole-table stage to the XLA gather (launch kind
+``shuffle_gather_xla``, so a run shows which route it took)."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
-from .. import record_launch
+from .. import interpret_mode, record_launch
 from .ref import shuffle_gather_ref
-from .shuffle_gather import BLOCK_ROWS, shuffle_gather
+from .shuffle_gather import BLOCK_ROWS, UNROLL, shuffle_gather
 
-VMEM_LIMIT_BYTES = 8 * 2**20
+# cap on the table's VMEM image: rows padded to the 8-sublane tile, columns
+# to the 128-lane tile (an (N, 1) column costs N * 512 bytes of VMEM)
+VMEM_TABLE_BYTES = 32 * 2**20
+
+
+def vmem_table_bytes(n: int, c: int, itemsize: int) -> int:
+    return -(-n // 8) * 8 * (-(-c // 128) * 128) * itemsize
 
 
 def gather_rows(table, perm, use_kernel: bool = True, block_rows: int = BLOCK_ROWS):
     """table: (N, C); perm: (N,) int32. Returns table[perm]."""
     n, c = table.shape
-    if not use_kernel or table.size == 0 or table.size * table.dtype.itemsize > VMEM_LIMIT_BYTES:
+    if not use_kernel or table.size == 0:
         return shuffle_gather_ref(table, perm)
+    block_rows = min(block_rows, max(UNROLL, 1 << (n - 1).bit_length()))
+    n_pad = -(-n // block_rows) * block_rows
+    if vmem_table_bytes(n_pad, c, table.dtype.itemsize) > VMEM_TABLE_BYTES:
+        record_launch("shuffle_gather_xla")
+        return shuffle_gather_ref(table, perm)
+    interpret = interpret_mode(table.dtype)
     record_launch("shuffle_gather")
-    block_rows = min(block_rows, max(8, 1 << (n - 1).bit_length()))
-    pad = (-n) % block_rows
+    pad = n_pad - n
     if pad:
         table_p = jnp.pad(table, ((0, pad), (0, 0)))
         perm_p = jnp.concatenate(
@@ -29,6 +40,6 @@ def gather_rows(table, perm, use_kernel: bool = True, block_rows: int = BLOCK_RO
     else:
         table_p, perm_p = table, perm.astype(jnp.int32)
     out = shuffle_gather(
-        table_p, perm_p, interpret=jax.default_backend() != "tpu", block_rows=block_rows
+        table_p, perm_p, interpret=interpret, block_rows=block_rows
     )
     return out[:n]

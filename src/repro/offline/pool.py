@@ -153,22 +153,27 @@ class RandomnessPool:
     # -- filling -------------------------------------------------------------
 
     def put(self, bundle_key: tuple, key: tuple, val: jax.Array) -> None:
-        """Insert template-static material (memo class)."""
-        nbytes = int(np.asarray(val).nbytes)
+        """Insert template-static material (memo class). The budget bounds
+        the in-flight bundle too: other bundles are evicted LRU-first to
+        make room, and a value that still does not fit is not kept (its
+        next fetch derives it on demand, bit-identically)."""
+        nbytes = int(val.nbytes)
         with self._lock:
             bundle = self._static.setdefault(bundle_key, {})
+            self._static.move_to_end(bundle_key)
             if key in bundle:
+                return
+            self._enforce_budget(protect=bundle_key, incoming=nbytes)
+            if self.total_bytes() + nbytes > self.max_bytes:
                 return
             bundle[key] = val
             self._static_bytes[bundle_key] = (
                 self._static_bytes.get(bundle_key, 0) + nbytes
             )
-            self._static.move_to_end(bundle_key)
-            self._enforce_budget(protect=bundle_key)
 
     def put_counter(self, key: tuple, val: jax.Array, ctr: int) -> None:
         """Insert counter-dependent material for a future counter."""
-        nbytes = int(np.asarray(val).nbytes)
+        nbytes = int(val.nbytes)
         with self._lock:
             if key in self._counter:
                 return
@@ -176,10 +181,13 @@ class RandomnessPool:
             self._counter_bytes += nbytes
             self._enforce_budget()
 
-    def _enforce_budget(self, protect: Optional[tuple] = None) -> None:
+    def _enforce_budget(
+        self, protect: Optional[tuple] = None, incoming: int = 0
+    ) -> None:
         # evict least-recently-used static bundles first (they can always be
-        # re-derived); counter entries expire via gc() instead
-        while self.total_bytes() > self.max_bytes and len(self._static) > (
+        # re-derived) until ``incoming`` more bytes fit; counter entries
+        # expire via gc() instead
+        while self.total_bytes() + incoming > self.max_bytes and len(self._static) > (
             1 if protect in self._static else 0
         ):
             for bk in self._static:
@@ -201,7 +209,7 @@ class RandomnessPool:
             dead = [k for k, (_, c) in self._counter.items() if c <= counter_watermark]
             for k in dead:
                 val, _ = self._counter.pop(k)
-                self._counter_bytes -= int(np.asarray(val).nbytes)
+                self._counter_bytes -= int(val.nbytes)
             for owned in self._provisioned.values():
                 owned.difference_update(
                     {c for c in owned if c <= counter_watermark}

@@ -21,7 +21,14 @@ from .coordinator import (
     launch_loopback_mesh,
 )
 from .exchange import RingExchange
-from .party import PartyServer, decode_table, encode_table
+from .party import (
+    PartyServer,
+    decode_table,
+    device_info,
+    encode_table,
+    party_env,
+    tpu_chip_env,
+)
 from .transport import (
     COORD,
     CTRL,
@@ -43,6 +50,9 @@ __all__ = [
     "launch_loopback_mesh",
     "RingExchange",
     "PartyServer",
+    "party_env",
+    "tpu_chip_env",
+    "device_info",
     "encode_table",
     "decode_table",
     "Transport",

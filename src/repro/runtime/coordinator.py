@@ -101,8 +101,10 @@ class Coordinator:
                 )
         return replies
 
-    def hello(self) -> None:
-        self._request_all({"type": "hello"})
+    def hello(self) -> List[Dict]:
+        """Handshake; each party's reply names the device it computes on
+        (``reply["device"]``: platform, kind, count)."""
+        return self._request_all({"type": "hello"})
 
     def load_tables(
         self,

@@ -30,10 +30,11 @@ tracer state means three party threads in one process stay fully isolated.
 from __future__ import annotations
 
 import contextlib
+import os
 import pickle
 import time
 import traceback
-from typing import Dict, Optional
+from typing import Dict, Mapping, Optional
 
 import jax
 import numpy as np
@@ -48,7 +49,59 @@ from ..ops.table import SecretTable
 from .exchange import RingExchange
 from .transport import COORD, CTRL, Transport
 
-__all__ = ["PartyServer", "encode_table", "decode_table"]
+__all__ = [
+    "PartyServer",
+    "encode_table",
+    "decode_table",
+    "device_info",
+    "party_env",
+    "tpu_chip_env",
+    "PARTY_PLATFORMS",
+]
+
+PARTY_PLATFORMS = ("cpu", "tpu")
+TPU_PROCESS_PORT_BASE = 8476  # libtpu's default port, one per chip process
+
+
+def party_env(
+    party: int, platform: str, base: Optional[Mapping[str, str]] = None
+) -> Dict[str, str]:
+    """Environment for one party process, explicit about its device.
+
+    ``cpu`` pins JAX to the CPU (CI's three-process runs). ``tpu`` gives the
+    process chip ``party`` of the host and nothing else, so three parties
+    hold three chips side by side (a process that sees the whole host would
+    take every chip and lock the others out). All three parties must get
+    the same platform: their replicated computations are audited for
+    equality byte by byte."""
+    if platform not in PARTY_PLATFORMS:
+        raise ValueError(f"party platform {platform!r} (expected cpu|tpu)")
+    env = dict(os.environ if base is None else base)
+    env["JAX_PLATFORMS"] = platform
+    if platform == "tpu":
+        env.update(tpu_chip_env(party))
+    return env
+
+
+def tpu_chip_env(chip: int) -> Dict[str, str]:
+    """libtpu variables that make a process see exactly one chip of a
+    multi-chip host."""
+    return {
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_VISIBLE_CHIPS": str(chip),
+        "TPU_PROCESS_PORT": str(TPU_PROCESS_PORT_BASE + chip),
+    }
+
+
+def device_info() -> Dict:
+    """The devices this process computes on, as JAX reports them."""
+    devs = jax.devices()
+    return {
+        "platform": devs[0].platform,
+        "kind": devs[0].device_kind,
+        "count": len(devs),
+    }
 
 
 def encode_table(table: SecretTable) -> Dict:
@@ -223,7 +276,11 @@ class PartyServer:
             mtype = msg.get("type")
             try:
                 if mtype == "hello":
-                    self._reply({"type": "hello_ack", "party": self.party})
+                    self._reply({
+                        "type": "hello_ack",
+                        "party": self.party,
+                        "device": device_info(),
+                    })
                 elif mtype == "load_tables":
                     self._reply(self._handle_load_tables(msg))
                 elif mtype == "execute":

@@ -272,4 +272,7 @@ def main(argv) -> int:
 
 
 if __name__ == "__main__":
+    from ..compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main(sys.argv[1:]))

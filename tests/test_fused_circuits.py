@@ -314,3 +314,55 @@ def test_share_parity_uses_matching_randomness():
     a1 = np.asarray(zero_share_xor(PRF.fold(101), shape, RING32))
     a2 = np.asarray(zero_share_xor(PRF.fold(102), shape, RING32))
     assert not np.array_equal(a1, a2)
+
+
+# -----------------------------------------------------------------------------
+# Sort and shuffle through their kernels: bit-identical shares and ledger
+# -----------------------------------------------------------------------------
+
+@pytest.mark.parametrize("key_cols", [("k",), ("k", "j")], ids=["1key", "2key"])
+def test_bitonic_sort_stage_kernel_parity(key_cols):
+    from repro.core.ledger import CommLedger
+    from repro.core.sort import bitonic_sort
+
+    n = 64
+    cols = {
+        name: share_b(rng.integers(0, 8, n).astype(np.uint32), jax.random.PRNGKey(i))
+        for i, name in enumerate(("k", "j", "v"))
+    }
+
+    def sort():
+        with CommLedger() as led:
+            out = bitonic_sort(cols, list(key_cols), PRF)
+        return out, led.by_op()
+
+    reset_launch_counts()
+    (f, f_led), (u, u_led) = _run(sort, True), _run(sort, False)
+    assert launch_counts()["bitonic_stage"] == 6 * 7 // 2  # one per stage
+    for name in cols:
+        np.testing.assert_array_equal(np.asarray(f[name].shares), np.asarray(u[name].shares))
+    assert f_led == u_led
+
+
+def test_secure_shuffle_gather_kernel_parity():
+    from repro.core.shuffle import HOPS, inverse_shuffle, secure_shuffle
+
+    n = 100
+    cols = {
+        "a": share_a(rng.integers(0, 1 << 20, n).astype(np.uint32), jax.random.PRNGKey(7)),
+        "b": share_b(rng.integers(0, 1 << 20, (n, 2)).astype(np.uint32), jax.random.PRNGKey(8)),
+    }
+
+    def round_trip():
+        fwd = secure_shuffle(cols, PRF)
+        return fwd, inverse_shuffle(fwd, PRF)
+
+    reset_launch_counts()
+    (f_fwd, f_back), (u_fwd, u_back) = _run(round_trip, True), _run(round_trip, False)
+    # one launch per column per hop, all three shares at once
+    assert launch_counts() == {"shuffle_gather": 2 * HOPS * len(cols)}
+    for name in cols:
+        np.testing.assert_array_equal(np.asarray(f_fwd[name].shares), np.asarray(u_fwd[name].shares))
+        np.testing.assert_array_equal(
+            np.asarray(f_back[name].shares), np.asarray(u_back[name].shares)
+        )

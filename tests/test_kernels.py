@@ -74,11 +74,17 @@ def test_shuffle_gather_sweep(n, c):
 
 
 def test_shuffle_gather_large_falls_back():
-    n, c = 4096, 600  # > VMEM_LIMIT -> XLA path
+    from repro.kernels import launch_counts, reset_launch_counts
+    from repro.kernels.shuffle_gather.ops import VMEM_TABLE_BYTES, vmem_table_bytes
+
+    n, c = 70000, 1  # VMEM image (rows x 128 lanes) > cap -> XLA path
+    assert vmem_table_bytes(n, c, 4) > VMEM_TABLE_BYTES
     t = rng.integers(0, 2**32, (n, c), dtype=np.uint32)
     p = rng.permutation(n).astype(np.int32)
+    reset_launch_counts()
     got = np.asarray(gather_rows(jnp.asarray(t), jnp.asarray(p)))
     np.testing.assert_array_equal(got, t[p])
+    assert launch_counts() == {"shuffle_gather_xla": 1}
 
 
 @pytest.mark.parametrize("n,c", [(128, 1), (512, 4), (100, 3)])

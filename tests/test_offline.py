@@ -158,9 +158,8 @@ def test_mixed_run_partial_pool_still_bit_identical(data):
 
 
 def test_pool_budget_evicts_static_bundles_not_correctness(data):
-    """A tiny budget evicts LRU *other* bundles (the in-flight one is
-    protected so a cold fill always completes) — and eviction only costs
-    future hits, never correctness."""
+    """A tiny budget evicts LRU *other* bundles (never the in-flight one) —
+    and eviction only costs future hits, never correctness."""
     tables, _ = data
     plan = EXACT_PLANS["filter"]()
     plan2 = Filter(Scan("medications"), [Predicate("med", "eq", 1)])
@@ -177,6 +176,28 @@ def test_pool_budget_evicts_static_bundles_not_correctness(data):
     assert stats["evictions"] > 0 and stats["bundles"] == 1  # b1 evicted
     for k, v in out_ref.reveal().items():
         np.testing.assert_array_equal(v, out.reveal()[k])
+
+
+def test_pool_budget_bounds_the_in_flight_bundle(data):
+    """One execution's static material never holds more than the budget:
+    what does not fit is not kept (device memory stays bounded however large
+    the query), and the next run derives it on demand, bit-identically."""
+    tables, _ = data
+    plan = EXACT_PLANS["filter"]()
+    out_ref, _ = _engine(tables).execute(plan)
+    _, full, _, _, _ = _recorded(tables, plan)
+    budget = full.total_bytes() // 2
+    pool = RandomnessPool(max_bytes=budget)
+    for _ in range(2):  # cold fill, then a partly hot run
+        eng = _engine(tables)
+        src = pool.source(("b",), eng.prf.pair_keys)
+        with material.material_scope(src):
+            out, _ = eng.execute(plan)
+        src.finish()
+        assert 0 < pool.total_bytes() <= budget
+        for k, v in out_ref.reveal().items():
+            np.testing.assert_array_equal(v, out.reveal()[k])
+    assert src.hits > 0 and src.misses > 0
 
 
 # -----------------------------------------------------------------------------

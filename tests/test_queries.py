@@ -105,3 +105,55 @@ def test_cost_model_estimates_and_placement():
         cost_model=cm,
     )
     assert "Resize" in p.pretty()
+
+
+# -----------------------------------------------------------------------------
+# The vectorized join oracles against the nested-loop definitions they replace
+# -----------------------------------------------------------------------------
+
+def _loop_oracle(query, plain):
+    from repro.data.healthlnk import (
+        DIAG_HEART_DISEASE,
+        DOSAGE_325MG,
+        ICD9_CIRCULATORY,
+        ICD9_HEART_414,
+        MED_ASPIRIN,
+    )
+
+    d, m = plain["diagnoses"], plain["medications"]
+    demo_pids = set(plain["demographics"]["pid"].tolist())
+    pids, pairs = set(), set()
+    for i in range(len(d["pid"])):
+        for j in range(len(m["pid"])):
+            if m["pid"][j] != d["pid"][i]:
+                continue
+            pid = int(d["pid"][i])
+            aspirin = m["med"][j] == MED_ASPIRIN
+            before = d["time"][i] <= m["time"][j]
+            if query == "dosage_study" and d["icd9"][i] == ICD9_CIRCULATORY \
+                    and aspirin and m["dosage"][j] == DOSAGE_325MG:
+                pids.add(pid)
+            if query == "aspirin_count" and d["icd9"][i] == ICD9_HEART_414 \
+                    and aspirin and before:
+                pids.add(pid)
+            if query == "three_join" and d["diag"][i] == DIAG_HEART_DISEASE \
+                    and aspirin and before and pid in demo_pids:
+                pids.add(pid)
+            if query == "projection_join" and aspirin:
+                pairs.add((pid, int(m["dosage"][j])))
+    if query == "dosage_study":
+        return sorted(pids)
+    if query == "projection_join":
+        return sorted(pairs)
+    return len(pids)
+
+
+@pytest.mark.parametrize(
+    "query", ["dosage_study", "aspirin_count", "three_join", "projection_join"]
+)
+def test_join_oracle_matches_nested_loop(query):
+    for seed in range(4):
+        _, plain = generate_healthlnk(
+            n=96, seed=seed, aspirin_frac=0.4, icd_heart_frac=0.3
+        )
+        assert plaintext_oracle(query, plain) == _loop_oracle(query, plain)

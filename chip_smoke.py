@@ -273,9 +273,7 @@ def default_phases(n_join: int, n: int, cross_device: bool) -> int:
     if cross_device:
         failures += not cross_device_check()
 
-    from repro.compile_cache import compile_cache_dir
-
-    cache = Path(compile_cache_dir())
+    cache = Path(jax.config.jax_compilation_cache_dir)
     entries = sum(1 for _ in cache.iterdir()) if cache.is_dir() else 0
     log(
         f"compile: {clock.seconds:.3f} s in all, {clock.compiles} backend compiles; "

@@ -9,13 +9,12 @@ One process per party (the production topology)::
 
 or a compose-style launcher that forks all three and waits::
 
-    PYTHONPATH=src python scripts/run_parties.py --party all [--platform cpu|tpu]
+    PYTHONPATH=src python scripts/run_parties.py --party all
 
-Each forked party gets an explicit device (``repro.runtime.party_env``):
-``--platform cpu`` (the default, CI's topology) pins all three to the CPU;
-``--platform tpu`` gives party p chip p of a multi-chip TPU host and
-nothing else. The launcher itself starts no JAX backend, so it holds no chip.
-A single ``--party p`` runs on whatever its environment selects.
+The forked parties are pinned to the CPU (``repro.runtime.party_env``), CI's
+topology; the launcher itself starts no JAX backend. A single ``--party p``
+runs on whatever its environment selects (``chip_smoke.py --parties`` gives
+each its own TPU chip).
 
 Parties listen on ``base_port + party`` and build the pair mesh among
 themselves (party p dials every lower-numbered party; higher-numbered
@@ -63,9 +62,9 @@ def serve_one(party: int, host: str, base_port: int) -> None:
     print(f"[party {party}] shut down", flush=True)
 
 
-def launch_all(host: str, base_port: int, platform: str) -> int:
-    """Compose-style launcher: three party processes, each on an explicit
-    device (see ``party_env``), torn down together."""
+def launch_all(host: str, base_port: int) -> int:
+    """Compose-style launcher: three party processes pinned to the CPU,
+    torn down together."""
     from repro.runtime.party import party_env
 
     procs = [
@@ -75,7 +74,7 @@ def launch_all(host: str, base_port: int, platform: str) -> int:
                 "--party", str(p), "--host", host,
                 "--base-port", str(base_port),
             ],
-            env=party_env(p, platform),
+            env=party_env(p, "cpu"),
         )
         for p in range(3)
     ]
@@ -100,11 +99,9 @@ def main() -> int:
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--base-port", type=int, default=9600,
                     help="party p listens on base-port + p (default 9600)")
-    ap.add_argument("--platform", choices=("cpu", "tpu"), default="cpu",
-                    help="device of each party forked by --party all")
     args = ap.parse_args()
     if args.party == "all":
-        return launch_all(args.host, args.base_port, args.platform)
+        return launch_all(args.host, args.base_port)
     from repro.compile_cache import enable_compile_cache
 
     enable_compile_cache()

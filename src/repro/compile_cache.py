@@ -12,13 +12,11 @@ name, a PID or the time.
 """
 from __future__ import annotations
 
-import os
 from pathlib import Path
-from typing import Mapping, Optional
 
 import jax
 
-__all__ = ["DEFAULT_CACHE_DIR", "compile_cache_dir", "enable_compile_cache"]
+__all__ = ["DEFAULT_CACHE_DIR", "enable_compile_cache"]
 
 DEFAULT_CACHE_DIR = str(Path(__file__).resolve().parents[2] / ".jax_cache")
 
@@ -27,17 +25,13 @@ DEFAULT_CACHE_DIR = str(Path(__file__).resolve().parents[2] / ".jax_cache")
 MIN_COMPILE_SECONDS = 0.1
 
 
-def compile_cache_dir(env: Optional[Mapping[str, str]] = None) -> str:
-    env = os.environ if env is None else env
-    return env.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_DIR
-
-
 def enable_compile_cache() -> str:
-    """Point JAX's persistent compilation cache at :func:`compile_cache_dir`
-    and return that directory."""
-    path = compile_cache_dir()
-    jax.config.update("jax_compilation_cache_dir", path)
+    """Turn JAX's persistent compilation cache on and return its directory:
+    the one ``JAX_COMPILATION_CACHE_DIR`` names (JAX reads the variable
+    itself), else :data:`DEFAULT_CACHE_DIR`."""
+    if not jax.config.jax_compilation_cache_dir:
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
     jax.config.update(
         "jax_persistent_cache_min_compile_time_secs", MIN_COMPILE_SECONDS
     )
-    return path
+    return jax.config.jax_compilation_cache_dir

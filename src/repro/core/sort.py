@@ -125,7 +125,10 @@ def _stage(
 
         alpha = zero_share_xor(p_swap, own.shape, own.ring)
         new = BShare(stage_swap(mask.shares, own.shares, other.shares, alpha))
-        log_comm("and", 1, own.size * own.ring.bytes)
+        # the AND's output is what the parties exchange
+        log_comm(
+            "and", 1, own.size * own.ring.bytes, payload=new.shares ^ own.shares
+        )
     else:
         m3 = BShare(jnp.broadcast_to(mask.shares[:, None, :], own.shares.shape))
         new = own ^ and_(m3, own ^ other, p_swap)

@@ -156,12 +156,13 @@ class RandomnessPool:
         """Insert template-static material (memo class). The budget bounds
         the in-flight bundle too: other bundles are evicted LRU-first to
         make room, and a value that still does not fit is not kept (its
-        next fetch derives it on demand, bit-identically)."""
+        next fetch derives it on demand, bit-identically). A value larger
+        than the whole budget evicts nothing."""
         nbytes = int(val.nbytes)
         with self._lock:
             bundle = self._static.setdefault(bundle_key, {})
             self._static.move_to_end(bundle_key)
-            if key in bundle:
+            if key in bundle or nbytes > self.max_bytes:
                 return
             self._enforce_budget(protect=bundle_key, incoming=nbytes)
             if self.total_bytes() + nbytes > self.max_bytes:

@@ -2,6 +2,8 @@
 the benchmark runner's exit code — the pieces that decide where and how the
 engine runs. CPU only: platforms other than the CPU are simulated by
 monkeypatching ``jax.default_backend``."""
+import os
+import subprocess
 import sys
 import types
 from pathlib import Path
@@ -57,36 +59,63 @@ def test_kernel_wrapper_raises_on_unsupported_platform(monkeypatch):
 # compile cache: env var honoured, else one fixed in-checkout directory
 # -----------------------------------------------------------------------------
 
-def test_compile_cache_dir_honours_env():
-    from repro.compile_cache import compile_cache_dir
+def _cache_dir_in_child(env_value):
+    """enable_compile_cache()'s directory, and JAX's setting after it, in a
+    fresh process started with the given JAX_COMPILATION_CACHE_DIR (None:
+    unset). Nothing is compiled there, so nothing is cached."""
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    if env_value is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_value
+    env["PYTHONPATH"] = str(REPO / "src")
+    code = (
+        "import jax; from repro.compile_cache import enable_compile_cache; "
+        "print(enable_compile_cache()); print(jax.config.jax_compilation_cache_dir)"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], env=env, capture_output=True, text=True,
+        check=True, timeout=120,
+    )
+    return out.stdout.split()
 
-    assert compile_cache_dir({"JAX_COMPILATION_CACHE_DIR": "/cache/x"}) == "/cache/x"
+
+def test_compile_cache_dir_honours_env():
+    assert _cache_dir_in_child("/cache/x") == ["/cache/x", "/cache/x"]
 
 
 def test_compile_cache_default_is_fixed_in_checkout():
-    from repro.compile_cache import DEFAULT_CACHE_DIR, compile_cache_dir
+    from repro.compile_cache import DEFAULT_CACHE_DIR
 
-    assert compile_cache_dir({}) == DEFAULT_CACHE_DIR
-    assert compile_cache_dir({"JAX_COMPILATION_CACHE_DIR": ""}) == DEFAULT_CACHE_DIR
     assert Path(DEFAULT_CACHE_DIR) == REPO / ".jax_cache"
     ignored = (REPO / ".gitignore").read_text().split()
     assert ".jax_cache/" in ignored
+    for unset in (None, ""):
+        assert _cache_dir_in_child(unset) == [DEFAULT_CACHE_DIR] * 2
+
+
+class _FakeConfig:
+    """Stands in for ``jax.config``: the cache directory JAX read from its
+    environment, and every update made."""
+
+    def __init__(self, cache_dir):
+        self.jax_compilation_cache_dir = cache_dir
+        self.calls = []
+
+    def update(self, name, value):
+        self.calls.append((name, value))
+        setattr(self, name, value)
 
 
 @pytest.mark.parametrize("env_dir", [None, "/cache/from-env"])
 def test_enable_compile_cache_sets_one_directory(monkeypatch, env_dir):
     from repro import compile_cache
 
-    if env_dir is None:
-        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    else:
-        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
-    calls = []
-    monkeypatch.setattr(jax.config, "update", lambda k, v: calls.append((k, v)))
+    config = _FakeConfig(env_dir)
+    monkeypatch.setattr(compile_cache, "jax", types.SimpleNamespace(config=config))
     path = compile_cache.enable_compile_cache()
     assert path == (env_dir or compile_cache.DEFAULT_CACHE_DIR)
-    dirs = [(k, v) for k, v in calls if k.endswith("_dir")]
-    assert dirs == [("jax_compilation_cache_dir", path)]
+    dirs = [(k, v) for k, v in config.calls if k.endswith("_dir")]
+    # a directory JAX already took from the environment is left alone
+    assert dirs == ([] if env_dir else [("jax_compilation_cache_dir", path)])
 
 
 # -----------------------------------------------------------------------------

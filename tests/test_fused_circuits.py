@@ -317,12 +317,31 @@ def test_share_parity_uses_matching_randomness():
 
 
 # -----------------------------------------------------------------------------
-# Sort and shuffle through their kernels: bit-identical shares and ledger
+# Sort and shuffle through their kernels: bit-identical shares, ledger and
+# exchanged payloads
 # -----------------------------------------------------------------------------
+
+
+def _payload_ledger():
+    """A CommLedger that also keeps every sync point's payload bytes (what a
+    networked party ships and checks where the point is not fused)."""
+    from repro.core.ledger import CommLedger
+
+    class PayloadLedger(CommLedger):
+        def __init__(self):
+            super().__init__()
+            self.payloads = []
+
+        def log(self, op, rounds, bytes_per_party, payload=None):
+            body = None if payload is None else np.asarray(payload).tobytes()
+            self.payloads.append((op, int(bytes_per_party), body))
+            super().log(op, rounds, bytes_per_party, payload)
+
+    return PayloadLedger()
+
 
 @pytest.mark.parametrize("key_cols", [("k",), ("k", "j")], ids=["1key", "2key"])
 def test_bitonic_sort_stage_kernel_parity(key_cols):
-    from repro.core.ledger import CommLedger
     from repro.core.sort import bitonic_sort
 
     n = 64
@@ -332,16 +351,20 @@ def test_bitonic_sort_stage_kernel_parity(key_cols):
     }
 
     def sort():
-        with CommLedger() as led:
+        with _payload_ledger() as led:
             out = bitonic_sort(cols, list(key_cols), PRF)
-        return out, led.by_op()
+        return out, led.by_op(), led.payloads
 
     reset_launch_counts()
-    (f, f_led), (u, u_led) = _run(sort, True), _run(sort, False)
+    (f, f_led, f_pay), (u, u_led, u_pay) = _run(sort, True), _run(sort, False)
     assert launch_counts()["bitonic_stage"] == 6 * 7 // 2  # one per stage
     for name in cols:
         np.testing.assert_array_equal(np.asarray(f[name].shares), np.asarray(u[name].shares))
     assert f_led == u_led
+    # each stage's swap AND carries its real output, as the gate path's does
+    swaps = [p for p in f_pay if p[0] == "and" and p[1] == 3 * n * 4]
+    assert len(swaps) == 6 * 7 // 2 and all(p[2] is not None for p in swaps)
+    assert f_pay == u_pay
 
 
 def test_secure_shuffle_gather_kernel_parity():

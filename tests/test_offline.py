@@ -158,24 +158,39 @@ def test_mixed_run_partial_pool_still_bit_identical(data):
 
 
 def test_pool_budget_evicts_static_bundles_not_correctness(data):
-    """A tiny budget evicts LRU *other* bundles (never the in-flight one) —
-    and eviction only costs future hits, never correctness."""
+    """A budget that holds either bundle but not both evicts the LRU *other*
+    bundle (never the in-flight one) to make room — and eviction only costs
+    future hits, never correctness."""
     tables, _ = data
     plan = EXACT_PLANS["filter"]()
     plan2 = Filter(Scan("medications"), [Predicate("med", "eq", 1)])
     out_ref, _ = _engine(tables).execute(plan)
-    pool = RandomnessPool(max_bytes=1)  # nothing fits once another arrives
+    b1 = _recorded(tables, plan)[1].total_bytes()
+    b2 = _recorded(tables, plan2)[1].total_bytes()
+    pool = RandomnessPool(max_bytes=max(b1, b2))
     eng = _engine(tables)
     src = pool.source(("b1",), eng.prf.pair_keys)
     with material.material_scope(src):
         out, _ = eng.execute(plan)
+    assert pool.total_bytes() == b1 > 0  # b1 held whole
     src2 = pool.source(("b2",), eng.prf.pair_keys)
     with material.material_scope(src2):
         eng.execute(plan2)
     stats = pool.stats()
-    assert stats["evictions"] > 0 and stats["bundles"] == 1  # b1 evicted
+    assert stats["evictions"] == 1 and stats["bundles"] == 1  # b1 evicted
+    assert pool.total_bytes() == b2 > 0  # to make room for b2, whole
     for k, v in out_ref.reveal().items():
         np.testing.assert_array_equal(v, out.reveal()[k])
+
+
+def test_pool_value_past_the_budget_evicts_nothing():
+    """A value that could never fit is dropped without emptying the pool."""
+    pool = RandomnessPool(max_bytes=64)
+    pool.put(("b1",), ("k",), jax.numpy.zeros(8, jax.numpy.uint32))
+    pool.put(("b2",), ("k",), jax.numpy.zeros(32, jax.numpy.uint32))
+    stats = pool.stats()
+    assert stats["evictions"] == 0 and stats["static_entries"] == 1
+    assert pool.total_bytes() == 32
 
 
 def test_pool_budget_bounds_the_in_flight_bundle(data):

@@ -136,6 +136,39 @@ def test_networked_config_is_shipped_to_parties(data):
         oracle.close()
 
 
+def test_networked_sort_under_pallas_matches_oracle(clients, data, monkeypatch):
+    """A sort-based GROUP BY with every kernel on (the sort's stages run as
+    bitonic_stage launches) on all three parties: rows and per-node ledger
+    equal the gate-path oracle's, and every party's wire bytes equal its
+    ledger bytes."""
+    from collections import Counter
+
+    from repro import kernels
+
+    launches = Counter()  # one count for the party threads together
+    monkeypatch.setattr(kernels, "_counter", lambda: launches)
+    oracle, _ = clients
+    tables, _ = data
+    networked = ReflexClient.networked(
+        tables, key_seed=0, config=RuntimeConfig(use_pallas=True)
+    )
+    try:
+        want = oracle.submit("tenant", GROUPBY_GOLDEN)
+        got = networked.submit("tenant", GROUPBY_GOLDEN)
+        assert launches["bitonic_stage"] > 0
+        assert_same_result(want, got)
+        tally = lambda r: [
+            (s.node, s.bytes_per_party, s.rounds) for s in r.report.nodes
+        ]
+        assert tally(want) == tally(got)
+        audit = networked.service.engine.last_wire_audit
+        assert [a["party"] for a in audit] == [0, 1, 2]
+        for a in audit:
+            assert a["wire_bytes"] == a["exchange_bytes"] == a["ledger_bytes"]
+    finally:
+        networked.close()
+
+
 # -----------------------------------------------------------------------------
 # Failure taxonomy
 # -----------------------------------------------------------------------------

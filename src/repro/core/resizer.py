@@ -67,7 +67,10 @@ FP_ONE = 1 << FP_BITS
 def oracle_true_count(table: SecretTable) -> int:
     """Plaintext T — simulation oracle only (used for the paper's runtime clip
     eta <- min(eta, N - T) and for tests; never enters the protocol view)."""
-    v = np.asarray(table.valid.shares)
+    from ..obs import trace as obs_trace
+
+    with obs_trace.span("device.wait", what="resize.count"):
+        v = np.asarray(table.valid.shares)
     return int(((v[0] ^ v[1] ^ v[2]) & 1).sum())
 
 
@@ -234,9 +237,11 @@ class Resizer:
         valid = shuffled.pop("__valid")
 
         # 4. reveal-and-trim: open k (the only disclosure), drop k=0 rows
-        k_open = np.asarray(
-            (k_col.shares[0] ^ k_col.shares[1] ^ k_col.shares[2]) & 1
-        )
+        from ..obs import trace as obs_trace
+
+        k_bits = (k_col.shares[0] ^ k_col.shares[1] ^ k_col.shares[2]) & 1
+        with obs_trace.span("device.wait", what="resize.open"):
+            k_open = np.asarray(k_bits)
         log_comm("reveal_k", 1, n * k_col.ring.bytes, payload=k_col.shares)
         s = int(k_open.sum())
         keep = np.nonzero(k_open)[0]

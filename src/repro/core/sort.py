@@ -19,6 +19,7 @@ from __future__ import annotations
 import math
 from typing import Dict, List, Sequence, Union
 
+import jax
 import jax.numpy as jnp
 
 from ..kernels import fusion_enabled
@@ -153,6 +154,8 @@ def bitonic_sort(
     led = active_ledger()
     import contextlib
 
+    from ..obs import trace as obs_trace
+
     n_stages = m * (m + 1) // 2
     # per-stage rounds: 6 (lt, all key columns in parallel) + 2 combining
     # levels per extra key (tie-AND + OR) + 1 select
@@ -162,7 +165,17 @@ def bitonic_sort(
         if led is not None
         else contextlib.nullcontext()
     )
-    with scope:
+    # Inside a jit or vmap trace the span would time the tracing, not the
+    # sort: open it only on concrete arrays.
+    traced = obs_trace.active_tracer() is not None and not any(
+        isinstance(c.shares, jax.core.Tracer) for c in cols.values()
+    )
+    timed = (
+        obs_trace.span("sort", n=n, stages=n_stages, key_cols=len(key_cols))
+        if traced
+        else contextlib.nullcontext()
+    )
+    with scope, timed:
         for k, j in bitonic_stages(n):
             cols = _stage(cols, key_cols, k, j, prf, descending)
     return cols

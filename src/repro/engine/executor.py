@@ -361,13 +361,17 @@ class Engine:
         drv = active_exchange()
         if drv is not None:
             x0 = (drv.count, drv.stall_seconds, drv.wire_bytes)
-        t0 = time.perf_counter()
-        with led:
-            out = self._apply(node, children)
-        _block(out)
-        dt = time.perf_counter() - t0
-        tally = led.tally()
         n_ins = [t.n for t in children]
+        t0 = time.perf_counter()
+        with obs_trace.span(
+            f"node[{node.label}]", op=node.describe(), n_ins=n_ins
+        ) as sp:
+            with led:
+                out = self._apply(node, children)
+            with obs_trace.span("device.wait", what="node"):
+                _block(out)
+        dt = sp.seconds if sp is not None else time.perf_counter() - t0
+        tally = led.tally()
         extra = {}
         if src is not None and (src.hits - h0 or src.misses - m0):
             # hot/cold attribution for EXPLAIN ANALYZE: how much of this
@@ -399,20 +403,15 @@ class Engine:
             rounds=int(tally["rounds"]),
             extra=extra,
         )
-        tr = obs_trace.active_tracer()
-        if tr is not None:
-            # `extra` passes the redaction boundary inside record(): the
-            # resizer's t/p/eta never reach the span, S and padding do.
-            tr.record(
-                f"node[{node.label}]",
-                seconds=dt,
-                op=node.describe(),
-                n_ins=n_ins,
-                n_out=stats.n_out,
-                bytes_per_party=stats.bytes_per_party,
-                rounds=stats.rounds,
-                **extra,
-            )
+        # `extra` passes the redaction boundary inside set_attrs(): the
+        # resizer's t/p/eta never reach the span, S and padding do.
+        obs_trace.set_attrs(
+            sp,
+            n_out=stats.n_out,
+            bytes_per_party=stats.bytes_per_party,
+            rounds=stats.rounds,
+            **extra,
+        )
         return out, stats
 
     def _run(self, node: PlanNode, report: ExecutionReport) -> SecretTable:
@@ -571,14 +570,21 @@ class Engine:
         led = CommLedger()
         src = material.active_source()
         h0, m0 = (src.hits, src.misses) if src is not None else (0, 0)
+        n_ins = [c.slot_n(0) for c in children]
         t0 = time.perf_counter()
-        with led:
-            out = self._apply_batched(node, [c.stacked for c in children], ctx.k)
-        jax.block_until_ready(out.valid.shares)
-        dt = time.perf_counter() - t0
+        with obs_trace.span(
+            f"node[{node.label}]", op=node.describe(), n_ins=list(n_ins),
+            slots=ctx.k, stacked=True,
+        ) as sp:
+            with led:
+                out = self._apply_batched(
+                    node, [c.stacked for c in children], ctx.k
+                )
+            with obs_trace.span("device.wait", what="node"):
+                _block(out)
+        dt = sp.seconds if sp is not None else time.perf_counter() - t0
         tally = led.tally()
         val = _BatchVal(k=ctx.k, stacked=out)
-        n_ins = [c.slot_n(0) for c in children]
         extra = {}
         if src is not None and (src.hits - h0 or src.misses - m0):
             # one vmapped launch serves all K slots: pool traffic is shared,
@@ -597,20 +603,13 @@ class Engine:
                     extra=dict(extra),
                 )
             )
-        tr = obs_trace.active_tracer()
-        if tr is not None:
-            tr.record(
-                f"node[{node.label}]",
-                seconds=dt,
-                op=node.describe(),
-                n_ins=list(n_ins),
-                n_out=val.slot_n(0),
-                bytes_per_party=int(tally["bytes_per_party"]),
-                rounds=int(tally["rounds"]),
-                slots=ctx.k,
-                stacked=True,
-                **extra,
-            )
+        obs_trace.set_attrs(
+            sp,
+            n_out=val.slot_n(0),
+            bytes_per_party=int(tally["bytes_per_party"]),
+            rounds=int(tally["rounds"]),
+            **extra,
+        )
         # physical cost of the pass: bytes x K, synchronous rounds shared
         phys = batched_tally(tally, ctx.k)
         bs = self.last_batch_stats
@@ -695,12 +694,12 @@ class Engine:
                     seconds=0.0, bytes_per_party=0, rounds=0,
                 )
             )
-        obs_trace.record(
+        with obs_trace.span(
             f"node[{node.label}]", op=node.describe(), n_ins=[],
             n_out=table.n, bytes_per_party=0, rounds=0,
             slots=ctx.k, stacked=True,
-        )
-        return _BatchVal(k=ctx.k, stacked=_broadcast_table(table, ctx.k))
+        ):
+            return _BatchVal(k=ctx.k, stacked=_broadcast_table(table, ctx.k))
 
     def _batch_resize(
         self, node: PlanNode, children: List[_BatchVal], ctx: _BatchCtx

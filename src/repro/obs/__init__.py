@@ -4,9 +4,10 @@ Three instruments behind one disclosure audit boundary
 (:mod:`repro.obs.redact`):
 
 * :mod:`repro.obs.trace` — hierarchical lifecycle spans (query -> compile ->
-  admit -> schedule.wait -> batch.flush -> execute -> node[op] -> reveal ->
-  record), thread-local like the :class:`~repro.core.ledger.CommLedger`,
-  exported as structured JSONL;
+  admit -> schedule.wait -> batch.flush -> execute -> node[op] -> sort /
+  device.wait / xla.compile -> reveal -> record), thread-local like the
+  :class:`~repro.core.ledger.CommLedger`, exported as structured JSONL and,
+  while a tracer is active, written into any profiler trace as host events;
 * :mod:`repro.obs.metrics` — a typed metrics registry (counters / gauges /
   histograms with audited label sets) rendered as Prometheus text exposition
   or a JSON snapshot;
@@ -30,7 +31,7 @@ from .distributed import (
 )
 from .explain import explain_text
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry
-from .trace import Span, Tracer, active_tracer, annotate, record, span
+from .trace import Span, Tracer, active_tracer, record, set_attrs, span
 
 __all__ = [
     "redact",
@@ -42,8 +43,8 @@ __all__ = [
     "Span",
     "Tracer",
     "active_tracer",
-    "annotate",
     "record",
+    "set_attrs",
     "span",
     "TraceContext",
     "WireMetricsPublisher",

@@ -114,6 +114,12 @@ PUBLIC_KEYS = frozenset({
     "stall_seconds", "retries", "backoff_seconds", "rejects", "connects",
     "seq", "queries", "mesh", "up", "clock_offset_s", "trace_id",
     "rtt_seconds", "parties", "spans", "merged",
+    # engine spans on the profiler's clock (DESIGN.md §14.1): `what` names
+    # the code site where the host waits on the device, and `phase` (above)
+    # the JAX compile phase, both fixed by the program; a sort's padded row
+    # count `n` (above), its `stages` and its `key_cols` count are public
+    # plan structure, functions of the oblivious capacity and the query
+    "what", "stages", "key_cols",
 })
 
 

@@ -253,11 +253,16 @@ class SecretTable:
 
     def reveal(self) -> Dict[str, np.ndarray]:
         """Open everything (tests / final results only)."""
-        out = {}
+        from ..obs import trace as obs_trace
+
+        opened = {}
         for k in self.cols:
             v = self.col(k)
-            out[k] = np.asarray(reveal_a(v) if isinstance(v, AShare) else reveal_b(v))
-        out["_valid"] = np.asarray(reveal_b(self.valid)) & 1
+            opened[k] = reveal_a(v) if isinstance(v, AShare) else reveal_b(v)
+        opened["_valid"] = reveal_b(self.valid)
+        with obs_trace.span("device.wait", what="reveal"):
+            out = {k: np.asarray(v) for k, v in opened.items()}
+        out["_valid"] = out["_valid"] & 1
         return out
 
     def reveal_true_rows(self) -> Dict[str, np.ndarray]:

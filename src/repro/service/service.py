@@ -324,61 +324,62 @@ class AnalyticsService:
         re-binds the cached physical plan (Resizer placement included)
         instead of recompiling."""
         t0 = time.perf_counter()
-        cm = default_cost_model(
-            self.catalog, noise=self.noise, calibration=self.calibration
-        )
-        logical = compile_logical(
-            sql, self.catalog, cost_model=cm, reorder_joins=self.reorder_joins
-        )
-        params = plan_params(logical)
-        cache_key = (
-            template_fingerprint(logical),
-            self.placement,
-            strategy_key(self.noise, self.addition),
-            self._shape_key(),
-        )
-        entry = self._plan_cache.get(cache_key)
-        hit = entry is not None
-        rebind = False
-        # the offline pool's bundle identity: same public template identity
-        # as the plan cache, hashed so it can double as a metric label
-        self._last_bundle_key = (
-            redact.fingerprint_hash(cache_key[0]), cache_key[3],
-        )
-        if hit:
-            self._plan_cache.move_to_end(cache_key)
-            self._m_plan_cache.inc(status="hit")
-            cached_params, cached_plan = entry
-            if params == cached_params:
-                plan = cached_plan  # identical query: shared plan object
-            else:
-                rebind = True
-                self._m_plan_cache.inc(status="rebind")
-                plan = bind_params(cached_plan, params)
-        else:
-            self._m_plan_cache.inc(status="miss")
-            # physical join selection BEFORE resizer placement, against the
-            # calibration-refined cost model: observed (already-disclosed)
-            # intermediate sizes steer the product-vs-sortmerge choice with
-            # zero extra disclosure. Catalogs without declared multiplicity
-            # bounds never rewrite (sort-merge inapplicable).
-            physical = select_join_algorithms(
-                logical, cost_model=cm, catalog=self.catalog,
-                mode=self.config.join_algo if self.config is not None else None,
+        with obs_trace.span("compile") as sp:
+            cm = default_cost_model(
+                self.catalog, noise=self.noise, calibration=self.calibration
             )
-            if self.placement == "none":
-                plan = physical
+            logical = compile_logical(
+                sql, self.catalog, cost_model=cm, reorder_joins=self.reorder_joins
+            )
+            params = plan_params(logical)
+            cache_key = (
+                template_fingerprint(logical),
+                self.placement,
+                strategy_key(self.noise, self.addition),
+                self._shape_key(),
+            )
+            entry = self._plan_cache.get(cache_key)
+            hit = entry is not None
+            rebind = False
+            # the offline pool's bundle identity: same public template identity
+            # as the plan cache, hashed so it can double as a metric label
+            self._last_bundle_key = (
+                redact.fingerprint_hash(cache_key[0]), cache_key[3],
+            )
+            if hit:
+                self._plan_cache.move_to_end(cache_key)
+                self._m_plan_cache.inc(status="hit")
+                cached_params, cached_plan = entry
+                if params == cached_params:
+                    plan = cached_plan  # identical query: shared plan object
+                else:
+                    rebind = True
+                    self._m_plan_cache.inc(status="rebind")
+                    plan = bind_params(cached_plan, params)
             else:
-                cfg = ResizerConfig(noise=self.noise, addition=self.addition)
-                plan = insert_resizers(
-                    physical, lambda _n: cfg, placement=self.placement,
-                    cost_model=cm,
+                self._m_plan_cache.inc(status="miss")
+                # physical join selection BEFORE resizer placement, against the
+                # calibration-refined cost model: observed (already-disclosed)
+                # intermediate sizes steer the product-vs-sortmerge choice with
+                # zero extra disclosure. Catalogs without declared multiplicity
+                # bounds never rewrite (sort-merge inapplicable).
+                physical = select_join_algorithms(
+                    logical, cost_model=cm, catalog=self.catalog,
+                    mode=self.config.join_algo if self.config is not None else None,
                 )
-            self._plan_cache[cache_key] = (params, plan)
-            while len(self._plan_cache) > self._plan_cache_max:
-                self._plan_cache.popitem(last=False)
+                if self.placement == "none":
+                    plan = physical
+                else:
+                    cfg = ResizerConfig(noise=self.noise, addition=self.addition)
+                    plan = insert_resizers(
+                        physical, lambda _n: cfg, placement=self.placement,
+                        cost_model=cm,
+                    )
+                self._plan_cache[cache_key] = (params, plan)
+                while len(self._plan_cache) > self._plan_cache_max:
+                    self._plan_cache.popitem(last=False)
         dt = time.perf_counter() - t0
-        obs_trace.record("compile", seconds=dt, cache_hit=hit, rebind=rebind)
+        obs_trace.set_attrs(sp, cache_hit=hit, rebind=rebind)
         return plan, hit, dt
 
     # -- the query path -------------------------------------------------------
@@ -389,18 +390,15 @@ class AnalyticsService:
         plan, hit, compile_s = self.compile(sql)
         bundle_key = self._last_bundle_key
         ta = time.perf_counter()
-        try:
-            admitted, escalations = self.accountant.admit(plan, planned)
-        except QueryRefused:
-            self._m_refusals.inc()
-            obs_trace.record(
-                "admit", seconds=time.perf_counter() - ta,
-                tenant=tenant, refused=True,
-            )
-            raise
-        obs_trace.record(
-            "admit", seconds=time.perf_counter() - ta,
-            tenant=tenant, refused=False, escalations=len(escalations),
+        with obs_trace.span("admit", tenant=tenant) as sp:
+            try:
+                admitted, escalations = self.accountant.admit(plan, planned)
+            except QueryRefused:
+                self._m_refusals.inc()
+                obs_trace.set_attrs(sp, refused=True)
+                raise
+        obs_trace.set_attrs(
+            sp, refused=False, escalations=len(escalations)
         )
         return AdmittedQuery(
             tenant=tenant,

@@ -111,14 +111,49 @@ def test_tracer_jsonl_round_trip(tmp_path):
     assert by_name["compile"]["attrs"]["cache_hit"] is True
 
 
-def test_tracer_annotate_merges_into_open_span():
+def test_record_starts_its_span_seconds_before_now():
     with Tracer() as tr:
-        with span("query") as sp:
-            from repro.obs import annotate
+        with span("before"):
+            pass
+        record("wait", seconds=0.25)
+        with span("after"):
+            pass
+    before, wait, after = tr.spans
+    end = wait.ts + wait.seconds
+    assert before.ts + before.seconds <= end <= after.ts
+    assert wait.ts == pytest.approx(end - 0.25)
 
-            annotate(cache_hit=True, t=99)  # t must be dropped
-    assert sp.attrs == {"cache_hit": True}
-    assert "t" in tr.redactions
+
+def test_set_attrs_merges_redacted_attrs_into_a_closed_span():
+    with Tracer() as tr:
+        with span("node[Resize]", op="Resize") as sp:
+            pass
+        from repro.obs import set_attrs
+
+        set_attrs(sp, s=23, t=9, eta=14)
+        set_attrs(None, s=1)  # what span() yields with tracing off
+    assert sp.attrs == {"op": "Resize", "s": 23}
+    assert sorted(tr.redactions) == ["eta", "t"]
+
+
+def test_compile_phases_are_spans_and_nest_as_jax_runs_them():
+    inner = jax.jit(lambda x: x * 3 + 1)
+    outer = jax.jit(lambda x: inner(x) - 2)
+    x = jnp.arange(5, dtype=jnp.int32).block_until_ready()
+    with Tracer() as tr:
+        with span("node[Probe]"):
+            outer(x).block_until_ready()
+    (node,) = tr.find("node[Probe]")
+    compiles = {s.span_id: s for s in tr.find("xla.compile")}
+    top = [s for s in compiles.values() if s.parent_id == node.span_id]
+    assert [s.attrs["phase"] for s in top] == ["trace", "lower", "backend"]
+    # the inner jit is traced while the outer one is: a child of that phase
+    nested = [s for s in compiles.values() if s.parent_id in compiles]
+    assert nested and all(
+        compiles[s.parent_id].attrs["phase"] == "trace" for s in nested
+    )
+    for s in top:
+        assert node.ts <= s.ts and s.ts + s.seconds <= node.ts + node.seconds + 1e-6
 
 
 # -----------------------------------------------------------------------------

@@ -10,25 +10,42 @@ oblivious select per payload column (1 AND-word). Total rounds
 O(log^2 N) — vs. the shuffle's O(1), which is exactly the paper's argument for
 replacing Shrinkwrap's sort with a shuffle (Fig. 5a / Fig. 8).
 
-The per-stage compare-exchange is the compute hot spot; with fused kernels on,
-its conditional swap runs as one ``repro.kernels.bitonic_stage`` launch, with
-this module's gate-by-gate path as the oracle.
+One network is one device program (``bitonic_network``): the columns ride
+it stacked as one (3, C, N) array, and the compare-exchange stage is written
+once, in a ``lax.scan`` over the network's (k, j) table, so a network
+compiles to the same program body whatever its stage count. The partner
+lane ``i ^ j`` is fetched by two lane rolls (``i + j`` where bit j of i is
+clear, ``i - j`` where it is set), so the program holds no gather. With
+fused kernels on, the stage's conditional swap is the
+``repro.kernels.bitonic_stage`` kernel, with the gate path as its oracle.
 """
 from __future__ import annotations
 
+import contextlib
+import functools
 import math
-from typing import Dict, List, Sequence, Union
+from typing import Dict, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
-from ..kernels import fusion_enabled
+from ..kernels import (
+    capture_launches,
+    fusion_enabled,
+    kernels_enabled,
+    override_fusion,
+    override_kernels,
+    record_launch,
+)
 from .circuits import and_bit, eq, lt, or_bit
-from .ledger import active_ledger, log_comm
+from .ledger import CommLedger, exchange_scope, log_comm
 from .prf import PRFSetup, zero_share_xor
 from .sharing import AShare, BShare, and_, const_b
 
 __all__ = [
+    "bitonic_network",
     "bitonic_sort",
     "bitonic_sort_narrow",
     "bitonic_stages",
@@ -49,25 +66,20 @@ def bitonic_stages(n: int):
         k *= 2
 
 
-def _lex_lt(
-    his: List[BShare], los: List[BShare], prf: PRFSetup
-) -> BShare:
-    """Lexicographic ``his < los`` over parallel key columns: column 0
-    decides unless it ties, in which case column 1 decides, and so on —
+def _lex_lt(his: BShare, los: BShare, prf: PRFSetup) -> BShare:
+    """Lexicographic ``his < los`` over K parallel key columns, given as
+    (3, K, n) sharings: column 0 decides unless it ties, in which case
+    column 1 decides, and so on —
     lt_0 OR (eq_0 AND lt_1) OR (eq_0 AND eq_1 AND lt_2) ...
 
     All columns' lt circuits (and all tie eq circuits) are independent, so
     they run as one batched call each — the rounds the ledger already models;
     only the shallow combine chain stays sequential."""
-    if len(his) == 1:
-        return lt(his[0], los[0], prf.fold(0))
-    h = BShare(jnp.stack([c.shares for c in his], axis=1))  # (3, K, n)
-    lo = BShare(jnp.stack([c.shares for c in los], axis=1))
-    lts = lt(h, lo, prf.fold(0))
-    eqs = eq(BShare(h.shares[:, :-1]), BShare(lo.shares[:, :-1]), prf.fold(6))
+    lts = lt(his, los, prf.fold(0))
+    eqs = eq(BShare(his.shares[:, :-1]), BShare(los.shares[:, :-1]), prf.fold(6))
     res = BShare(lts.shares[:, 0])
     ties = None
-    for i in range(1, len(his)):
+    for i in range(1, his.shape[0]):
         p = prf.fold(i)
         e = BShare(eqs.shares[:, i - 1])
         ties = e if ties is None else and_bit(ties, e, p.fold(2))
@@ -77,63 +89,128 @@ def _lex_lt(
 
 
 def _stage(
-    cols: Dict[str, BShare],
-    key_cols: Sequence[str],
-    k: int,
-    j: int,
+    own: jnp.ndarray,
+    n_keys: int,
+    k: jnp.ndarray,
+    j: jnp.ndarray,
     prf: PRFSetup,
     descending: bool,
-) -> Dict[str, BShare]:
-    keyb = cols[key_cols[0]]
-    n = keyb.shape[0]
-    idx = jnp.arange(n)
-    partner = idx ^ j
-    is_lo = idx < partner  # public lane predicate
+) -> jnp.ndarray:
+    """One compare-exchange stage over the stacked (3, C, n) shares, the
+    first ``n_keys`` columns the sort key; ``k`` and ``j`` are traced."""
+    n = own.shape[-1]
+    idx = lax.iota(jnp.int32, n)
+    is_lo = (idx & j) == 0  # public lane predicate: partner i ^ j = i + j
     asc = (idx & k) == 0  # public direction per pair (bit k equal for both)
     if descending:
         asc = ~asc
+    # partner lanes by roll: roll(-j) at the lower lane, roll(+j) at the upper
+    both = jnp.concatenate([own, own], axis=-1)
+    other = jnp.where(
+        is_lo,
+        lax.dynamic_slice_in_dim(both, j, n, axis=-1),
+        lax.dynamic_slice_in_dim(both, n - j, n, axis=-1),
+    )
 
     # lo/hi views on public masks (local): lo = value at the lower lane index
-    def lo_hi(col: BShare):
-        a = col  # own value
-        b = col.take(partner, axis=0)  # partner value
-        return (
-            BShare(jnp.where(is_lo, a.shares, b.shares)),
-            BShare(jnp.where(is_lo, b.shares, a.shares)),
-        )
-
-    los, his = zip(*(lo_hi(cols[kc]) for kc in key_cols))
+    los = BShare(jnp.where(is_lo, own[:, :n_keys], other[:, :n_keys]))
+    his = BShare(jnp.where(is_lo, other[:, :n_keys], own[:, :n_keys]))
     # swap decision, identical at both lanes of the pair (ties don't swap)
     p = prf.fold(7 * k + j)
-    if len(key_cols) == 1:
-        s = lt(his[0], los[0], p)  # hi < lo -> out of order (asc)
+    if n_keys == 1:
+        s = lt(BShare(his.shares[:, 0]), BShare(los.shares[:, 0]), p)
     else:
-        s = _lex_lt(list(his), list(los), p)
+        s = _lex_lt(his, los, p)
     # descending pairs invert the decision (local XOR with a public bit)
     s = s.xor_public(jnp.where(asc, 0, 1).astype(s.ring.dtype))
     mask = s.lsb_mask()
 
     # conditional swap of every column in one batched AND (per-column selects
     # are independent; same words, one dispatch)
-    names = list(cols)
-    own = BShare(jnp.stack([cols[nm].shares for nm in names], axis=1))  # (3,C,n)
-    other = own.take(partner, axis=1)
+    own_b = BShare(own)
     p_swap = prf.fold(9000 + 31 * k + 7 * j)
     if fusion_enabled():
         # the same AND (same alpha, same ledger entry) with the select's
-        # XORs fused around it: one bitonic_stage launch
+        # XORs fused around it: the bitonic_stage kernel
         from ..kernels.bitonic_stage.ops import stage_swap
 
-        alpha = zero_share_xor(p_swap, own.shape, own.ring)
-        new = BShare(stage_swap(mask.shares, own.shares, other.shares, alpha))
-        # the AND's output is what the parties exchange
-        log_comm(
-            "and", 1, own.size * own.ring.bytes, payload=new.shares ^ own.shares
+        alpha = zero_share_xor(p_swap, own_b.shape, own_b.ring)
+        log_comm("and", 1, own_b.size * own_b.ring.bytes)
+        return stage_swap(mask.shares, own, other, alpha)
+    m3 = BShare(jnp.broadcast_to(mask.shares[:, None, :], own.shape))
+    return (own_b ^ and_(m3, own_b ^ BShare(other), p_swap)).shares
+
+
+@contextlib.contextmanager
+def _stage_trace(kernels: bool, fusion: bool):
+    """Trace stage bodies on the given gate path, off the caller's ledger,
+    exchange boundary and launch counts; yields the private ledger and
+    launch counter that take what the body logs and launches."""
+    with contextlib.ExitStack() as stack:
+        stack.enter_context(exchange_scope(None))
+        led = stack.enter_context(CommLedger())
+        launches = stack.enter_context(capture_launches())
+        stack.enter_context(override_kernels(kernels))
+        stack.enter_context(override_fusion(fusion))
+        yield led, launches
+
+
+@functools.partial(
+    jax.jit, static_argnames=("n_keys", "descending", "kernels", "fusion")
+)
+def bitonic_network(
+    own: jnp.ndarray,
+    pair_keys: jnp.ndarray,
+    n_keys: int,
+    descending: bool,
+    kernels: bool,
+    fusion: bool,
+) -> jnp.ndarray:
+    """The whole bitonic network over the stacked (3, C, n) shares, sorted by
+    their first ``n_keys`` columns, as one program: ``lax.scan`` over the
+    network's (k, j) table runs the one traced stage body. ``kernels`` and
+    ``fusion`` are the gate path the body is traced with (they change the
+    program, so they are part of its cache key). The body's single trace
+    pass logs and launches nothing: ``bitonic_sort`` counts every stage."""
+    n = own.shape[-1]
+    table = np.array(list(bitonic_stages(n)), dtype=np.int32).reshape(-1, 2)
+    prf = PRFSetup(pair_keys)
+
+    def body(carry, kj):
+        return _stage(carry, n_keys, kj[0], kj[1], prf, descending), None
+
+    with _stage_trace(kernels, fusion):
+        out, _ = lax.scan(body, own, jnp.asarray(table))
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def _stage_cost(
+    shape: tuple,
+    dtype: str,
+    keys_shape: tuple,
+    keys_dtype: str,
+    n_keys: int,
+    descending: bool,
+    kernels: bool,
+    fusion: bool,
+) -> Tuple[int, Tuple[Tuple[str, int], ...]]:
+    """Bytes per party that one stage of the network sends, and the kernel
+    launches it makes, from one abstract trace of the stage body."""
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+
+    def stage(own, pair_keys, k, j):
+        return _stage(own, n_keys, k, j, PRFSetup(pair_keys), descending)
+
+    with _stage_trace(kernels, fusion) as (led, launches):
+        jax.eval_shape(
+            stage,
+            jax.ShapeDtypeStruct(shape, dtype),
+            jax.ShapeDtypeStruct(keys_shape, keys_dtype),
+            scalar,
+            scalar,
         )
-    else:
-        m3 = BShare(jnp.broadcast_to(mask.shares[:, None, :], own.shares.shape))
-        new = own ^ and_(m3, own ^ other, p_swap)
-    return {nm: BShare(new.shares[:, i]) for i, nm in enumerate(names)}
+    return int(led.tally()["bytes_per_party"]), tuple(sorted(launches.items()))
 
 
 def bitonic_sort(
@@ -145,40 +222,58 @@ def bitonic_sort(
     """Sort all columns by ``key_col`` (32-bit unsigned order) — a single
     column name or a sequence of names compared lexicographically (composite
     GROUP BY keys). N must be a power of two (the engine's bucketing
-    guarantees this)."""
+    guarantees this).
+
+    The ledger gets one ``bitonic_sort`` entry per sort, as the network's
+    stages would have logged it: ``rounds_per_stage * stages`` rounds and
+    the stage's bytes times the stage count."""
     key_cols = [key_col] if isinstance(key_col, str) else list(key_col)
     n = next(iter(cols.values())).shape[0]
     if n & (n - 1):
         raise ValueError(f"bitonic_sort requires power-of-two rows, got {n}")
     m = int(math.log2(n))
-    led = active_ledger()
-    import contextlib
-
     from ..obs import trace as obs_trace
 
     n_stages = m * (m + 1) // 2
     # per-stage rounds: 6 (lt, all key columns in parallel) + 2 combining
     # levels per extra key (tie-AND + OR) + 1 select
     rounds_per_stage = 7 + 2 * (len(key_cols) - 1)
-    scope = (
-        led.fused("bitonic_sort", rounds=rounds_per_stage * n_stages)
-        if led is not None
-        else contextlib.nullcontext()
+    if n_stages == 0:
+        log_comm("bitonic_sort", 0, 0)
+        return cols
+    names = key_cols + [nm for nm in cols if nm not in key_cols]
+    own = jnp.stack([cols[nm].shares for nm in names], axis=1)  # (3, C, n)
+    static = dict(
+        n_keys=len(key_cols),
+        descending=descending,
+        kernels=kernels_enabled(),
+        fusion=fusion_enabled(),
     )
     # Inside a jit or vmap trace the span would time the tracing, not the
     # sort: open it only on concrete arrays.
-    traced = obs_trace.active_tracer() is not None and not any(
-        isinstance(c.shares, jax.core.Tracer) for c in cols.values()
+    traced = obs_trace.active_tracer() is not None and not isinstance(
+        own, jax.core.Tracer
     )
     timed = (
         obs_trace.span("sort", n=n, stages=n_stages, key_cols=len(key_cols))
         if traced
         else contextlib.nullcontext()
     )
-    with scope, timed:
-        for k, j in bitonic_stages(n):
-            cols = _stage(cols, key_cols, k, j, prf, descending)
-    return cols
+    with timed:
+        out = bitonic_network(own, prf.pair_keys, **static)
+    stage_bytes, stage_launches = _stage_cost(
+        own.shape,
+        jnp.dtype(own.dtype).name,
+        prf.pair_keys.shape,
+        jnp.dtype(prf.pair_keys.dtype).name,
+        **static,
+    )
+    log_comm("bitonic_sort", rounds_per_stage * n_stages, stage_bytes * n_stages)
+    # the network program runs the stage body's kernels once per stage
+    for kind, count in stage_launches:
+        record_launch(kind, count * n_stages)
+    sorted_cols = {nm: BShare(out[:, i]) for i, nm in enumerate(names)}
+    return {nm: sorted_cols[nm] for nm in cols}
 
 
 def bitonic_sort_narrow(

@@ -44,7 +44,10 @@ Launch accounting
 Every ``ops.py`` wrapper records the kernel dispatches it issues from Python
 (trace-time accounting: a jit-cached re-execution of an enclosing function is
 not re-counted — the engine's protocol layer runs eagerly by default, where
-the count equals real dispatches). ``launch_counts()`` is what
+the count equals real dispatches). A bitonic sort's network program traces
+its stage body once for all its stages, so ``core.sort`` takes that pass's
+launches off the count with :func:`capture_launches` and records one stage's
+launches once per stage. ``launch_counts()`` is what
 ``benchmarks/bench_kernels.py`` uses to demonstrate the fused-kernel launch
 reduction.
 """
@@ -133,7 +136,20 @@ def _counter() -> Counter:
 
 
 def record_launch(kind: str, n: int = 1) -> None:
-    _counter()[kind] += n
+    sink = getattr(_STATE, "sink", None)
+    (_counter() if sink is None else sink)[kind] += n
+
+
+@contextlib.contextmanager
+def capture_launches() -> Iterator[Counter]:
+    """Count this thread's launches inside the block into a fresh counter,
+    yielded, instead of :func:`launch_counts`."""
+    prev = getattr(_STATE, "sink", None)
+    _STATE.sink = Counter()
+    try:
+        yield _STATE.sink
+    finally:
+        _STATE.sink = prev
 
 
 def launch_counts() -> Dict[str, int]:

@@ -33,7 +33,8 @@ monitoring events: the first :meth:`Tracer.__enter__` registers one listener
 for their start (a scalar event) and one for their duration, and each phase
 becomes a span (and an annotation) from start to end, under whatever span was
 innermost. Its ``seconds`` is JAX's own duration, so the spans sum to what a
-listener of those events sums. A phase that JAX runs inside another (a jit
+listener of those events sums, and it starts at JAX's own start time (the
+start event's value), so consecutive phases do not overlap. A phase that JAX runs inside another (a jit
 traced while another is traced or lowered) is its child.
 
 Every attribute dict passes through :func:`repro.obs.redact.public_view`
@@ -257,16 +258,23 @@ def _open_compiles() -> list:
     return _STATE.compiles
 
 
-def _on_compile_start(event: str, _value, **_kw) -> None:
-    # JAX marks the start of each phase with a scalar event (its start time)
+def _on_compile_start(event: str, start: float, **_kw) -> None:
+    # JAX marks the start of each phase with a scalar event: its start time
+    # on time.time(), taken before the listeners run
     if not event.startswith(_COMPILE_EVENT):
         return
     tr = active_tracer()
     if tr is None:
         return
+    now, now_wall = time.perf_counter(), time.time()
     phase = _COMPILE_PHASES.get(event[len(_COMPILE_EVENT):], "other")
     cm = tr.span("xla.compile", phase=phase)
-    _open_compiles().append((event, cm, cm.__enter__()))
+    sp = cm.__enter__()
+    # the span's seconds is JAX's duration from that start: start it there,
+    # not when this listener runs, or it would end past JAX's end and
+    # overlap the phase that follows
+    sp.ts = tr._epoch + now - max(0.0, now_wall - start)
+    _open_compiles().append((event, cm, sp))
 
 
 def _on_compile_end(event: str, duration: float, **_kw) -> None:

@@ -5,8 +5,9 @@ this template draw, per node?" — counted at the **eager call-site
 granularity** the ambient :mod:`repro.core.material` hook intercepts:
 ``PRFSetup.fold`` / ``draw`` / ``draw_uniform``, ``zero_share_add/xor``,
 and shuffle-hop permutations. (Gate-internal zero-sharings that live
-inside jitted whole-level payloads compile into the program and are
-neither intercepted nor counted — see DESIGN.md §15.1.)
+inside jitted whole-level payloads, and every derivation inside a bitonic
+sort's network program, compile into the program and are neither
+intercepted nor counted — see DESIGN.md §15.1.)
 
 Counts are a pure function of the template and its pow2-bucketed shapes.
 For the operators whose derivation stream is simple enough to enumerate
@@ -22,7 +23,6 @@ service exports them per template through EXPLAIN and the
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Dict, Optional, Tuple
 
 from ..core.noise import NoTrim
@@ -217,12 +217,11 @@ class RandomnessPlanner:
         return self._pred_counts(node.pred, node.child)
 
     def _count_GroupByCount(self, node) -> Dict[str, int]:
-        # sort-based: keys ride the bitonic network (stage folds), payload
-        # gathered once via shuffle-apply (6 hop perms). Sizing estimate.
-        k = max(1, int(math.log2(max(2, _bucket_pow2(self._rows(node))))))
-        stages = k * (k + 1) // 2
+        # sort-based: keys ride the bitonic network, whose stage folds are
+        # compiled into its program and never fetched; payload gathered once
+        # via shuffle-apply (6 hop perms). Sizing estimate.
         return dict(
-            folds=2 * stages + 12,
+            folds=12,
             perms=2 * SHUFFLE_HOPS,
             conversions=2,
             exact=False,
@@ -250,10 +249,9 @@ class RandomnessPlanner:
         return dict(folds=8, exact=False)
 
     def _count_JoinSortMerge(self, node) -> Dict[str, int]:
-        k = max(1, int(math.log2(max(2, _bucket_pow2(self._rows(node))))))
-        stages = k * (k + 1) // 2
+        # the union's sort folds inside its network program, as above
         return dict(
-            folds=2 * stages + 24,
+            folds=24,
             perms=2 * SHUFFLE_HOPS,
             conversions=2,
             exact=False,

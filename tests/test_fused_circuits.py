@@ -358,13 +358,17 @@ def test_bitonic_sort_stage_kernel_parity(key_cols):
     reset_launch_counts()
     (f, f_led, f_pay), (u, u_led, u_pay) = _run(sort, True), _run(sort, False)
     assert launch_counts()["bitonic_stage"] == 6 * 7 // 2  # one per stage
+    # a cached network program counts its stages the same as a fresh trace
+    _run(sort, True)
+    assert launch_counts()["bitonic_stage"] == 2 * 6 * 7 // 2
     for name in cols:
         np.testing.assert_array_equal(np.asarray(f[name].shares), np.asarray(u[name].shares))
     assert f_led == u_led
-    # each stage's swap AND carries its real output, as the gate path's does
-    swaps = [p for p in f_pay if p[0] == "and" and p[1] == 3 * n * 4]
-    assert len(swaps) == 6 * 7 // 2 and all(p[2] is not None for p in swaps)
-    assert f_pay == u_pay
+    # the network is one program: its stages' messages ride one payload-less
+    # sort entry, 21 stages of the lt and the 3-column select words
+    k = len(key_cols)
+    words = 11 * k + 7 * (k - 1) + 3
+    assert f_pay == u_pay == [("bitonic_sort", 21 * words * n * 4, None)]
 
 
 def test_secure_shuffle_gather_kernel_parity():

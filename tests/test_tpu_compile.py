@@ -94,3 +94,26 @@ def test_shuffle_gather_compiles_at_vmem_cap(one_chip):
     table = _spec(one_chip, (GATHER_ROWS, 3))
     perm = _spec(one_chip, (GATHER_ROWS,), jnp.int32)
     _compile(lambda t, p: shuffle_gather(t, p, interpret=False), table, perm)
+
+
+@pytest.mark.parametrize(
+    "rows,cols,n_keys",
+    [(1 << 17, 3, 2), (1 << 21, 2, 1)],
+    ids=["join-2^17", "distinct-2^21"],
+)
+def test_bitonic_network_compiles_looped(one_chip, rows, cols, n_keys):
+    """The study's largest sort networks (a two-key join union, a Distinct
+    over the padded join output) compile for the chip as one program on the
+    default gate path, with the stage body in one loop and no gather."""
+    from repro.core.sort import bitonic_network
+
+    compiled = bitonic_network.lower(
+        _spec(one_chip, (3, cols, rows)),
+        _spec(one_chip, (3, 2)),
+        n_keys=n_keys,
+        descending=False,
+        kernels=False,
+        fusion=False,
+    ).compile()
+    text = compiled.as_text()
+    assert "while" in text and "gather" not in text
